@@ -1,7 +1,8 @@
 """Benchmark harness: one module per paper table/figure + the TPU-serving
 integration and the roofline analysis.  Prints ``name,us_per_call,derived``
 CSV rows (us_per_call = harness wall time per run; derived = the figure's
-metrics)."""
+metrics).  A module that raises gets a ``<name>.ERROR`` row, the remaining
+modules still run, and the harness exits nonzero."""
 from __future__ import annotations
 
 import sys
@@ -39,15 +40,19 @@ def main() -> None:
         ("roofline", roofline.main),
     ]
     only = sys.argv[1] if len(sys.argv) > 1 else None
+    failed = []
     for name, fn in modules:
         if only and only not in name:
             continue
         try:
             rows.extend(fn())
-        except Exception as e:  # noqa: BLE001 — report and continue
+        except Exception as e:  # noqa: BLE001 — report, continue, fail below
             traceback.print_exc(file=sys.stderr)
             rows.append((f"{name}.ERROR", 0.0, repr(e)[:120]))
+            failed.append(name)
     emit(rows)
+    if failed:
+        sys.exit(f"benchmark modules raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

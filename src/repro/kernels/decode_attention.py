@@ -33,7 +33,7 @@ def _dec_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     q = q_ref[0].astype(jnp.float32)  # (SUB, D) — row 0 is real
     k = k_ref[0].astype(jnp.float32)  # (bk, D)
     v = v_ref[0].astype(jnp.float32)
-    kv_len = len_ref[0]
+    kv_len = len_ref[pl.program_id(0)]
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -77,7 +77,9 @@ def decode_attention(q, k, v, kv_len, *, scale=None, bk=512, interpret=False):
         kernel,
         grid=(B * H, n_kv),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, j: (b,), memory_space=pltpu.SMEM),
+            # whole (B*H,) length vector in SMEM: a (1,) block of a rank-1
+            # SMEM array is refused by the TPU compiler
+            pl.BlockSpec((B * H,), lambda b, j: (0,), memory_space=pltpu.SMEM),
             pl.BlockSpec((1, SUB, D), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, bk, D), lambda b, j: (b, j, 0)),

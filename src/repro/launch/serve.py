@@ -1,11 +1,16 @@
 """Serving driver: multi-tenant continuous batching with LAGS admission.
 
   PYTHONPATH=src python -m repro.launch.serve --policy lags --tenants 40 \
-      --duration 30 --real-model
+      --duration 30 --real-model --arch qwen3-8b --reduced --max-len 64
 
-``--real-model`` attaches a reduced decoder so every engine step also runs a
-jitted decode over the shared KV cache (proving the engine drives real
-compute); without it the calibrated step-cost model is used (fast sweeps).
+``--real-model`` attaches the ``--arch`` decoder (random weights from
+``--seed``) so every engine step that runs a batch also decodes one token
+per slot over a ``--slots`` x ``--max-len`` KV cache on the device; without
+it the calibrated step-cost model is used (fast sweeps).  ``--reduced``
+swaps in the tiny float32 same-family config for CPU runs; without it the
+model runs at full width and depth in its config dtype.  The run prints
+how many of its batch steps decoded on the device: the two part once the
+cache is full.
 
 Telemetry: ``--obs-dir DIR`` records the run (schedstats + metrics) as a
 diffable run record; ``--trace`` additionally captures a Chrome trace-event
@@ -75,7 +80,14 @@ def main(argv=None):
                     help="tenant count at which the credit tick moves onto "
                          "the fused Pallas kernel (0 = never)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--real-model", action="store_true")
+    ap.add_argument("--real-model", action="store_true",
+                    help="decode on the --arch model every batch step")
+    ap.add_argument("--arch", default="stablelm-1.6b",
+                    help="registered model config for --real-model")
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny float32 same-family config (CPU smoke runs)")
+    ap.add_argument("--max-len", type=int, default=64,
+                    help="KV-cache tokens per slot for --real-model")
     ap.add_argument("--obs-dir", default="",
                     help="record schedstats/metrics run record here")
     ap.add_argument("--trace", action="store_true",
@@ -141,9 +153,11 @@ def main(argv=None):
         from repro.configs.base import get_config, reduced
         from repro.models import model as model_lib
 
-        cfg = reduced(get_config("qwen3-8b"), n_layers=2)
-        params = model_lib.init_params(cfg, jax.random.PRNGKey(0))
-        eng.attach_model(cfg, params, max_len=64)
+        cfg = get_config(args.arch)
+        if args.reduced:
+            cfg = reduced(cfg)
+        params = model_lib.init_params(cfg, jax.random.PRNGKey(args.seed))
+        eng.attach_model(cfg, params, max_len=args.max_len)
 
     meta = {
         "layer": "serving", "policy": args.policy,
@@ -195,6 +209,17 @@ def main(argv=None):
            if (st.fenced_steps or st.deferred) else "")
         + (f" checkpoints={n_ckpt}" if n_ckpt else "")
     )
+    if args.real_model:
+        wall_ms = np.median(st.decode_wall_s) * 1e3 if st.decode_wall_s \
+            else -1.0
+        print(
+            f"device decodes={st.device_decodes}/{st.batch_steps} batch steps "
+            f"nonfinite={st.nonfinite_decodes} "
+            f"median_decode_wall={wall_ms:.3f}ms"
+            + (f" (cache of {args.max_len} full: later steps ran on the "
+               "cost model only)"
+               if st.device_decodes < st.batch_steps else "")
+        )
     if args.obs_dir:
         path = record_run(
             args.obs_dir,
@@ -208,4 +233,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     main()

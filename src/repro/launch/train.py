@@ -108,4 +108,7 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     main()

@@ -1,8 +1,8 @@
 """GQA attention with chunked online-softmax, sliding windows and KV cache.
 
-The pure-XLA path below is the dry-run / CPU reference; on TPU the same
-contraction is served by ``repro.kernels.flash_attention`` (prefill) and
-``repro.kernels.decode_attention`` (decode) — selected via ``use_pallas``.
+Every model path, on CPU and TPU, runs the pure-XLA contraction below; the
+Pallas kernels ``repro.kernels.flash_attention`` (prefill) and
+``repro.kernels.decode_attention`` (decode) are not wired into it yet.
 Queries are processed in chunks under ``lax.scan`` so the score matrix never
 materialises beyond (B, Hkv, G, chunk, Skv), bounding live memory the same
 way a flash kernel bounds VMEM.

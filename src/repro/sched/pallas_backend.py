@@ -9,16 +9,20 @@ replaces the O(T) Python EMA loop, and the returned pick order is exactly
 the LAGS admission order the engine applies next step.
 
 Off-TPU the kernel runs in Pallas interpret mode (bit-compatible, slow) —
-``tick_and_pick`` picks the mode from the active JAX backend, so tests and
-CPU smoke runs exercise the identical kernel code path.
+``interpret_default`` picks the mode from the active JAX backend, and is the
+repository's one such choice: tests and CPU smoke runs exercise the
+identical kernel code path, and on a TPU the kernel runs compiled.
 
 ``numpy_reference`` is the float64 oracle for the cross-backend
 differential tests.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.load_credit import (
@@ -27,21 +31,22 @@ from repro.core.load_credit import (
     ema_update,
     pelt_update,
 )
+from repro.kernels.lags_select import lags_select
 
 
-def available() -> bool:
-    try:
-        import jax  # noqa: F401
-        from jax.experimental import pallas  # noqa: F401
-    except Exception:  # pragma: no cover - jax is baked into the image
-        return False
-    return True
-
-
-def _interpret_default() -> bool:
-    import jax
-
+def interpret_default() -> bool:
+    """Run Pallas kernels in interpret mode unless the backend is a TPU."""
     return jax.default_backend() != "tpu"
+
+
+@functools.partial(
+    jax.jit, static_argnames=("k", "window", "halflife", "interpret"))
+def tick(load_avg, credit, running_frac, runnable, *, k: int, window: int,
+         halflife: int, interpret: bool):
+    """The jitted tick ``tick_and_pick`` runs: (T,) float32 state and a
+    (T,) bool runnable mask in, ``lags_select``'s outputs out."""
+    return lags_select(load_avg, credit, running_frac, runnable, k,
+                       window=window, halflife=halflife, interpret=interpret)
 
 
 def tick_and_pick(load_avg, credit, running_frac, runnable, k: int, *,
@@ -55,18 +60,14 @@ def tick_and_pick(load_avg, credit, running_frac, runnable, k: int, *,
     is ascending updated credit, ties broken by group index — identical
     to the numpy backend's LAGS admission order.
     """
-    import jax.numpy as jnp
-
-    from repro.kernels.lags_select import lags_select
-
     if interpret is None:
-        interpret = _interpret_default()
-    nl, nc, idx = lags_select(
+        interpret = interpret_default()
+    nl, nc, idx = tick(
         jnp.asarray(load_avg, jnp.float32),
         jnp.asarray(credit, jnp.float32),
         jnp.asarray(running_frac, jnp.float32),
-        jnp.asarray(runnable),
-        k, window=window, halflife=halflife, interpret=interpret,
+        jnp.asarray(runnable, bool),
+        k=k, window=window, halflife=halflife, interpret=interpret,
     )
     return np.asarray(nl), np.asarray(nc), np.asarray(idx)
 

@@ -12,11 +12,15 @@ both the rate and the per-switch cost versus fair round-robin admission.
 Two execution backends:
   * ``step_cost_model`` (default) — calibrated analytic step times (CPU-fast;
     used by benchmarks to sweep density like Fig 3/9).
-  * a real jitted ``decode_step`` over a reduced model (``attach_model``) —
-    used by tests/examples to prove the engine drives real compute.
+  * a real jitted ``decode_step`` over an attached model (``attach_model``):
+    every step that runs a batch also decodes one token per slot on the
+    device.  ``EngineStats.device_decodes`` counts those decodes beside
+    ``batch_steps``; once the dense cache is full the device stops and the
+    two counts part, so a run that outlived its cache shows it.
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
@@ -86,6 +90,14 @@ class EngineStats:
         self.sched = SchedStats("engine")
         self.time_s = 0.0
         self.steps = 0
+        # steps that ran a batch, and of those the ones decoded on an
+        # attached model (equal unless the model's cache ran out)
+        self.batch_steps = 0
+        self.device_decodes = 0
+        # device decodes whose logits held a NaN/inf, and the host wall
+        # time of each device decode up to reading its result back
+        self.nonfinite_decodes = 0
+        self.decode_wall_s: List[float] = []
         self.completed: List[Request] = []
         # graceful-degradation counters (also published as obs metrics
         # ``engine.shed`` / ``engine.expired`` / ``engine.backoff``)
@@ -117,6 +129,25 @@ class EngineStats:
     @property
     def overhead_frac(self) -> float:
         return self.switch_s / max(self.time_s, 1e-12)
+
+
+def decode_and_pick(model_cfg):
+    """The engine's jitted device step: decode one token for every slot,
+    pick the next tokens greedily, and report whether every logit is
+    finite."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import model as model_lib
+
+    def step(params, tokens, cache, cache_len):
+        logits, cache = model_lib.decode_step(
+            params, model_cfg, {"tokens": tokens}, cache, cache_len
+        )
+        nxt = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+        return nxt, jnp.isfinite(logits).all(), cache
+
+    return jax.jit(step)
 
 
 class Engine:
@@ -165,7 +196,6 @@ class Engine:
 
     # -- optional real-model backend ------------------------------------
     def attach_model(self, model_cfg, params, max_len: int = 256):
-        import jax
         import jax.numpy as jnp
 
         from repro.models import model as model_lib
@@ -174,14 +204,7 @@ class Engine:
         self._cache = model_lib.init_cache(model_cfg, self.cfg.n_slots, max_len)
         self._tokens = jnp.zeros((self.cfg.n_slots, 1), jnp.int32)
         self._cache_len = 0
-
-        def _step(params, tokens, cache, cache_len):
-            # model_lib.decode_step(params, cfg, batch, cache, cache_len)
-            return model_lib.decode_step(
-                params, model_cfg, {"tokens": tokens}, cache, cache_len
-            )
-
-        self._decode = jax.jit(_step)
+        self._decode = decode_and_pick(model_cfg)
 
     def submit(self, req: Request):
         self.tenants[req.tenant].queue.append(req)
@@ -339,6 +362,7 @@ class Engine:
         # step time: decode for the batch + chunked prefill work
         compute_s = cfg.base_step_s * (len(self.running) / cfg.n_slots) ** 0.5
         compute_s += cfg.per_prefill_tok_s * prefill_toks
+        st.batch_steps += 1
         if self._model is not None:
             self._real_decode()
 
@@ -367,11 +391,7 @@ class Engine:
             served[r.tenant] = served.get(r.tenant, 0.0) + service_per_req
         for tid, s in served.items():
             st.sched.account_useful(tid, s)
-        if (
-            cfg.pallas_threshold
-            and len(self.tenants) >= cfg.pallas_threshold
-            and pallas_backend.available()
-        ):
+        if cfg.pallas_threshold and len(self.tenants) >= cfg.pallas_threshold:
             self._pallas_tick(served, step_s)
         else:
             for tid, t in self.tenants.items():
@@ -531,13 +551,18 @@ class Engine:
     def _real_decode(self):
         import jax.numpy as jnp
 
-        model_cfg, params, max_len = self._model
+        _, params, max_len = self._model
         if self._cache_len >= max_len - 1:
-            return
-        logits, self._cache = self._decode(
+            return  # cache full: device_decodes stops counting
+        st = self.stats
+        t0 = time.perf_counter()
+        self._tokens, finite, self._cache = self._decode(
             params, self._tokens, self._cache, jnp.asarray(self._cache_len)
         )
-        self._tokens = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+        if not bool(finite):  # waits for the decode to finish
+            st.nonfinite_decodes += 1
+        st.decode_wall_s.append(time.perf_counter() - t0)
+        st.device_decodes += 1
         self._cache_len += 1
 
     def run(self, until_s: float, arrivals: Optional[List[Request]] = None,

@@ -66,7 +66,6 @@ def test_shard_map_single_device():
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
     from repro.distributed.ep_a2a import moe_ep_a2a_local
 
     rng = np.random.default_rng(2)
@@ -78,7 +77,7 @@ def test_shard_map_single_device():
     wg, wu, wd = w(E, M, F), w(E, M, F), w(E, F, M)
 
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("model",))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda *a: moe_ep_a2a_local(*a, axis_name="model", capacity_factor=2.0),
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P()),

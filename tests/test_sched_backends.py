@@ -147,10 +147,7 @@ def test_preemption_boundary_equal_credits_never_fires():
 
 # -- Pallas backend ---------------------------------------------------------
 
-pallas_ok = pb.available()
 
-
-@pytest.mark.skipif(not pallas_ok, reason="pallas unavailable")
 def test_pallas_tick_matches_numpy_reference():
     """The fused kernel agrees with the float64 oracle: identical pick
     order, allclose credit state, on 20 randomized cases."""
@@ -176,7 +173,6 @@ def test_pallas_tick_matches_numpy_reference():
         assert idx.tolist() == ridx.tolist(), f"case {case}"
 
 
-@pytest.mark.skipif(not pallas_ok, reason="pallas unavailable")
 def test_engine_pallas_tick_matches_python_tick():
     """Engine state after one _pallas_tick == one python Tenant.tick loop."""
     from repro.serving.engine import Engine, EngineConfig
@@ -215,7 +211,6 @@ def test_engine_pallas_tick_matches_python_tick():
         [tb[i].served_s for i in range(n)]
 
 
-@pytest.mark.skipif(not pallas_ok, reason="pallas unavailable")
 def test_engine_pallas_path_completes_like_python_path():
     from repro.serving.engine import Engine, EngineConfig
 
