@@ -25,6 +25,14 @@ Fleet observability: ``record_dir`` makes every simulated node emit a run
 record (``node<i>/run.json``); render the merged fleet view with
 
   python -m repro.obs.report --merge RECORD_DIR/node*
+
+Each call also opens ``repro.obs`` spans, which land in any
+``jax.profiler`` trace on the profiler's clock: ``fleet.call`` (both
+backends) and, in the JAX path, ``fleet.workload`` and
+``fleet.slot_trace`` per node, ``fleet.stage`` (padding and stacking),
+``fleet.scan`` around ``fleet.dispatch``, and ``fleet.readback``.  Their
+args count what each stage builds and the bytes it moves between host and
+device, from shapes alone, so no span waits on the device.
 """
 from __future__ import annotations
 
@@ -37,6 +45,7 @@ import numpy as np
 from repro.core.simkernel import SimConfig, SimResult, simulate
 from repro.core.traces import make_workload
 from repro.fleet.placement import Assignment
+from repro.obs import tracing as obs_tracing
 from repro.obs.schedstats import SchedStats
 from repro.sched.numpy_backend import make_policy
 
@@ -197,53 +206,85 @@ def _fleet_sim_jax(policy_name: str, counts: np.ndarray, duration_s: float,
     node_rates = rates if rates is not None else [None] * len(counts)
     node_fids = fn_ids if fn_ids is not None else [None] * len(counts)
     node_extra = extra if extra is not None else [None] * len(counts)
-    traces = []
+    traces, requests = [], 0
     for k, seed, ex, r, fids, xt in zip(counts, seeds, execs, node_rates,
                                         node_fids, node_extra):
-        wl = make_workload(
-            "azure2021", int(k), duration_s=duration_s, n_cores=n_cores,
-            seed=seed, exec_s=ex, threads_per_fn=threads_per_fn, rates=r,
-            fn_ids=fids, extra=xt,
-        )
-        traces.append(sj.build_slot_trace(wl, int(k), threads_per_fn))
+        with obs_tracing.span("fleet.workload", "fleet", fns=int(k)) as sp:
+            wl = make_workload(
+                "azure2021", int(k), duration_s=duration_s,
+                n_cores=n_cores, seed=seed, exec_s=ex,
+                threads_per_fn=threads_per_fn, rates=r, fn_ids=fids,
+                extra=xt,
+            )
+            n_req = sum(len(a) for a in wl.arrivals)
+            sp.set_args(requests=n_req)
+        requests += n_req
+        with obs_tracing.span("fleet.slot_trace", "fleet") as sp:
+            trace = sj.build_slot_trace(wl, int(k), threads_per_fn)
+            sp.set_args(slots=int(trace.arrival_tick.shape[0]),
+                        depth=int(trace.arrival_tick.shape[1]),
+                        h2d_bytes=_nbytes(trace))
+        traces.append(trace)
     max_fns = int(max(counts))
     T = max_fns * threads_per_fn
     R = max(int(t.arrival_tick.shape[1]) for t in traces)
-    padded = [_pad_trace(t, T, R) for t in traces]
-    stacked = jax.tree_util.tree_map(
-        lambda *xs: jnp.stack(xs), *padded
-    )
+    n = len(traces)
+    # _pad_trace reads each trace back and uploads it padded to (T, R)
+    with obs_tracing.span(
+            "fleet.stage", "fleet", nodes=n, slots=T, depth=R,
+            requests=requests, padded=n * T * R,
+            d2h_bytes=sum(_nbytes(t) for t in traces),
+            h2d_bytes=n * (T * R * 8 + T * 4)):
+        padded = [_pad_trace(t, T, R) for t in traces]
+        stacked = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs), *padded
+        )
     p = sj.SimParams(
         n_cores=n_cores, n_fns=max_fns,
         n_ticks=int(duration_s / sj.TICK), policy=CODE_OF[policy_name],
         burst_us=280.0, depth=5.0,
     )
-    out = jax.vmap(lambda t: sj.simulate(t, p))(stacked)
+    with obs_tracing.span("fleet.scan", "fleet", nodes=n, ticks=p.n_ticks):
+        with obs_tracing.span("fleet.dispatch", "fleet"):
+            out = jax.vmap(lambda t: sj.simulate(t, p))(stacked)
+        jax.block_until_ready(out)
 
+    # read back per node: its completion ticks, its padded arrival ticks
+    # (twice) and slot map, and its two counters
     results = []
     BIG = np.iinfo(np.int32).max // 2
-    for i, trace in enumerate(padded):
-        done = np.asarray(out["done_tick"][i])
-        lat = sj.latencies_from(trace, done)
-        at = np.asarray(trace.arrival_tick)
-        ok = (done >= 0) & (at < BIG)
-        fn_of = np.broadcast_to(
-            np.asarray(trace.slot_fn)[:, None], at.shape
-        )[ok]
-        results.append(SimResult(
-            policy=policy_name,
-            latencies=lat,
-            fn_of=fn_of,
-            arrival_of=at[ok] * sj.TICK,
-            n_arrived=int((at < BIG).sum()),
-            n_completed=len(lat),
-            switches=0,
-            switch_time_s=float(out["overhead_s"][i]),
-            busy_time_s=float(out["busy_s"][i]),
-            duration_s=duration_s,
-            n_cores=n_cores,
-        ))
+    with obs_tracing.span(
+            "fleet.readback", "fleet",
+            d2h_bytes=int(out["done_tick"].nbytes + out["busy_s"].nbytes
+                          + out["overhead_s"].nbytes
+                          + 2 * stacked.arrival_tick.nbytes
+                          + stacked.slot_fn.nbytes)):
+        for i, trace in enumerate(padded):
+            done = np.asarray(out["done_tick"][i])
+            lat = sj.latencies_from(trace, done)
+            at = np.asarray(trace.arrival_tick)
+            ok = (done >= 0) & (at < BIG)
+            fn_of = np.broadcast_to(
+                np.asarray(trace.slot_fn)[:, None], at.shape
+            )[ok]
+            results.append(SimResult(
+                policy=policy_name,
+                latencies=lat,
+                fn_of=fn_of,
+                arrival_of=at[ok] * sj.TICK,
+                n_arrived=int((at < BIG).sum()),
+                n_completed=len(lat),
+                switches=0,
+                switch_time_s=float(out["overhead_s"][i]),
+                busy_time_s=float(out["busy_s"][i]),
+                duration_s=duration_s,
+                n_cores=n_cores,
+            ))
     return results
+
+
+def _nbytes(trace) -> int:
+    return int(sum(x.nbytes for x in trace))
 
 
 def simulate_fleet(
@@ -280,74 +321,76 @@ def simulate_fleet(
     ``node_rates``) adds exact-count replay arrivals per function — the
     chaos layer's retry-backlog and epoch-carryover channel.
     """
-    counts = assignment.counts
-    assert int(counts.sum()) == int(assignment.shares.shape[0]), (
-        "placement dropped functions"  # Assignment already guards this
-    )
-    seeds = [seed + i if distinct_seeds else seed
-             for i in range(assignment.n_nodes)]
-    mult = (np.ones(assignment.n_nodes) if node_exec_mult is None
-            else np.asarray(node_exec_mult, float))
-    is_dead = (np.zeros(assignment.n_nodes, bool) if dead is None
-               else np.asarray(dead, bool))
-    rate_of = (node_rates if node_rates is not None
-               else [None] * assignment.n_nodes)
-    extra_of = (node_extra if node_extra is not None
-                else [None] * assignment.n_nodes)
-    live = [(i, int(k)) for i, k in enumerate(counts)
-            if k > 0 and not is_dead[i]]
-    fids_of = [
-        np.asarray(assignment.node_fns[i], np.int64)
-        if rate_of[i] is not None else None
-        for i in range(assignment.n_nodes)
-    ]
-    if backend == "jax":
-        tpf = threads_per_fn or 8
-        sims = _fleet_sim_jax(
-            policy_name, np.asarray([k for _, k in live]), duration_s,
-            n_cores, [seeds[i] for i, _ in live],
-            [exec_s * float(mult[i]) for i, _ in live], tpf,
-            rates=[rate_of[i] for i, _ in live],
-            fn_ids=[fids_of[i] for i, _ in live],
-            extra=[extra_of[i] for i, _ in live],
+    with obs_tracing.span("fleet.call", "fleet", nodes=assignment.n_nodes,
+                          backend=backend):
+        counts = assignment.counts
+        assert int(counts.sum()) == int(assignment.shares.shape[0]), (
+            "placement dropped functions"  # Assignment already guards this
         )
-        by_node = {i: r for (i, _), r in zip(live, sims)}
-    elif backend == "numpy":
-        tpf = threads_per_fn or 192
-        cache: Dict[Tuple, SimResult] = {}
-        by_node = {}
-        for i, k in live:
-            r = rate_of[i]
-            key = (k, int(seeds[i]), float(mult[i]),
-                   None if r is None else hash(np.asarray(r).tobytes()),
-                   None if fids_of[i] is None
-                   else hash(fids_of[i].tobytes()),
-                   None if extra_of[i] is None
-                   else hash(np.asarray(extra_of[i], np.int64).tobytes()))
-            if key not in cache:
-                cache[key] = _node_sim_numpy(
-                    policy_name, k, duration_s, n_cores, int(seeds[i]),
-                    exec_s * float(mult[i]), tpf, rates=r,
-                    fn_ids=fids_of[i], extra=extra_of[i],
-                )
-            by_node[i] = cache[key]
-    else:
-        raise ValueError(f"unknown backend {backend!r}")
-    nodes = [
-        by_node.get(i) or _empty_node(policy_name, duration_s, n_cores,
-                                      backend)
-        for i in range(assignment.n_nodes)
-    ]
+        seeds = [seed + i if distinct_seeds else seed
+                 for i in range(assignment.n_nodes)]
+        mult = (np.ones(assignment.n_nodes) if node_exec_mult is None
+                else np.asarray(node_exec_mult, float))
+        is_dead = (np.zeros(assignment.n_nodes, bool) if dead is None
+                   else np.asarray(dead, bool))
+        rate_of = (node_rates if node_rates is not None
+                   else [None] * assignment.n_nodes)
+        extra_of = (node_extra if node_extra is not None
+                    else [None] * assignment.n_nodes)
+        live = [(i, int(k)) for i, k in enumerate(counts)
+                if k > 0 and not is_dead[i]]
+        fids_of = [
+            np.asarray(assignment.node_fns[i], np.int64)
+            if rate_of[i] is not None else None
+            for i in range(assignment.n_nodes)
+        ]
+        if backend == "jax":
+            tpf = threads_per_fn or 8
+            sims = _fleet_sim_jax(
+                policy_name, np.asarray([k for _, k in live]), duration_s,
+                n_cores, [seeds[i] for i, _ in live],
+                [exec_s * float(mult[i]) for i, _ in live], tpf,
+                rates=[rate_of[i] for i, _ in live],
+                fn_ids=[fids_of[i] for i, _ in live],
+                extra=[extra_of[i] for i, _ in live],
+            )
+            by_node = {i: r for (i, _), r in zip(live, sims)}
+        elif backend == "numpy":
+            tpf = threads_per_fn or 192
+            cache: Dict[Tuple, SimResult] = {}
+            by_node = {}
+            for i, k in live:
+                r = rate_of[i]
+                key = (k, int(seeds[i]), float(mult[i]),
+                       None if r is None else hash(np.asarray(r).tobytes()),
+                       None if fids_of[i] is None
+                       else hash(fids_of[i].tobytes()),
+                       None if extra_of[i] is None
+                       else hash(np.asarray(extra_of[i], np.int64).tobytes()))
+                if key not in cache:
+                    cache[key] = _node_sim_numpy(
+                        policy_name, k, duration_s, n_cores, int(seeds[i]),
+                        exec_s * float(mult[i]), tpf, rates=r,
+                        fn_ids=fids_of[i], extra=extra_of[i],
+                    )
+                by_node[i] = cache[key]
+        else:
+            raise ValueError(f"unknown backend {backend!r}")
+        nodes = [
+            by_node.get(i) or _empty_node(policy_name, duration_s, n_cores,
+                                          backend)
+            for i in range(assignment.n_nodes)
+        ]
 
-    fleet = FleetResult(
-        policy=policy_name,
-        placement=assignment.placement,
-        nodes=nodes,
-        counts=counts,
-        duration_s=duration_s,
-        n_cores=n_cores,
-        backend=backend,
-    )
+        fleet = FleetResult(
+            policy=policy_name,
+            placement=assignment.placement,
+            nodes=nodes,
+            counts=counts,
+            duration_s=duration_s,
+            n_cores=n_cores,
+            backend=backend,
+        )
     if record_dir:
         record_fleet(fleet, record_dir)
     return fleet

@@ -1,15 +1,20 @@
-"""Bounded ring-buffer event tracer with Chrome trace-event JSON export.
+"""Spans with two sinks: the profiler's trace and a bounded ring buffer.
 
-Spans are recorded as complete ("ph": "X") trace events into a fixed-size
-ring; ``export()`` writes the Chrome trace-event format that Perfetto /
-chrome://tracing load directly.  ``fenced_span`` is the JAX-aware timer: the
-caller registers jitted outputs on the fence and the span closes only after
+Every span enters a ``jax.profiler.TraceAnnotation`` of its name, with its
+args as the event's stats, so a ``jax.profiler`` session (``start_trace``,
+TensorBoard, the benchmark's traced run) sees the program's spans on the
+profiler's own clock, beside the device's operations.  The annotation is
+written whether or not a ring tracer is installed; with no profile being
+captured it costs about a microsecond.  ``jax`` is imported on the first
+span, so importing ``repro.obs`` does not import it.
+
+The ring (``Tracer``) records spans as complete ("ph": "X") trace events
+into a fixed-size buffer; ``export()`` writes the Chrome trace-event format
+that Perfetto / chrome://tracing load directly.  It is off unless installed
+(``install()``).  ``fenced_span`` is the JAX-aware timer: the caller
+registers jitted outputs on the fence and the span closes only after
 ``jax.block_until_ready`` — otherwise async dispatch makes a jitted step
 look ~free.
-
-The tracer is off unless installed (``install()``); the module-level
-``span``/``fenced_span`` helpers degrade to no-ops, so instrumented hot
-paths cost one check per call when tracing is disabled.
 """
 from __future__ import annotations
 
@@ -20,6 +25,17 @@ from typing import Deque, List, Optional
 
 from repro.obs import metrics as _metrics
 
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _annotation(name: str, args: dict):
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name, **args)
+
 
 class Span:
     """Context manager for one complete trace event.
@@ -28,10 +44,11 @@ class Span:
     outputs to block on (``fence(x)`` returns ``x``), and the duration is
     measured after ``block_until_ready``.  ``dur_s`` is valid after exit
     even when the owning tracer is a no-op, so callers can feed metrics.
+    The profiler annotation is open from enter to exit (after the fence).
     """
 
     __slots__ = ("tracer", "name", "cat", "args", "fenced", "_pending",
-                 "_t0_ns", "dur_s")
+                 "_t0_ns", "dur_s", "_note")
 
     def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
                  fenced: bool = False, **args):
@@ -49,7 +66,15 @@ class Span:
             self._pending.append(x)
         return x
 
+    def set_args(self, **args) -> None:
+        """Add args known only once the span's work is done (a count of
+        what it built); call before exit."""
+        self.args.update(args)
+        self._note.set_metadata(**args)
+
     def __enter__(self) -> "Span":
+        self._note = _annotation(self.name, self.args)
+        self._note.__enter__()
         self._t0_ns = time.perf_counter_ns()
         return self
 
@@ -60,6 +85,7 @@ class Span:
             jax.block_until_ready(self._pending)
             self._pending.clear()
         dt_ns = time.perf_counter_ns() - self._t0_ns
+        self._note.__exit__(*exc)
         self.dur_s = dt_ns * 1e-9
         if self.tracer is not None:
             self.tracer.emit(
@@ -82,9 +108,6 @@ class Tracer:
     def __len__(self) -> int:
         return len(self._events)
 
-    def now_us(self) -> float:
-        return (time.perf_counter_ns() - self._t0_ns) / 1e3
-
     def emit(self, name: str, cat: str, ts_us: float, dur_us: float,
              args: Optional[dict] = None, ph: str = "X", tid: int = 0) -> None:
         if len(self._events) == self.capacity:
@@ -105,13 +128,6 @@ class Tracer:
     def fenced_span(self, name: str, cat: str = "jax", **args) -> Span:
         return Span(self, name, cat, fenced=True, **args)
 
-    def instant(self, name: str, cat: str = "obs", **args) -> None:
-        self.emit(name, cat, self.now_us(), 0.0, args, ph="i")
-
-    def counter(self, name: str, **series: float) -> None:
-        """Chrome counter-track sample (renders as a stacked area chart)."""
-        self.emit(name, "counter", self.now_us(), 0.0, series, ph="C")
-
     def events(self) -> List[dict]:
         return sorted(self._events, key=lambda e: e["ts"])
 
@@ -128,8 +144,6 @@ class Tracer:
 
 
 _TRACER: Optional[Tracer] = None
-
-_NULL_SPAN_ARGS = dict(tracer=None, name="", cat="")
 
 
 def install(capacity: int = 65536) -> Tracer:

@@ -2,7 +2,9 @@
 
 1. Puts ``src/`` on ``sys.path`` so plain ``pytest`` works without setting
    ``PYTHONPATH=src`` by hand.
-2. Provides a *functional* ``hypothesis`` stand-in when the real package is
+2. Provides the ``profiled`` fixture: run a callable under a
+   ``jax.profiler`` trace and read back the host plane's spans.
+3. Provides a *functional* ``hypothesis`` stand-in when the real package is
    not installed (the container image has no network; see
    requirements-dev.txt).  Unlike the old shim — which collected property
    tests only to skip them — this mini-engine actually runs each
@@ -19,7 +21,10 @@ from __future__ import annotations
 import os
 import sys
 import types
+import warnings
 import zlib
+
+import pytest
 
 sys.path.insert(
     0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
@@ -210,3 +215,29 @@ except ModuleNotFoundError:
     _mod.strategies = _st
     sys.modules["hypothesis"] = _mod
     sys.modules["hypothesis.strategies"] = _st
+
+
+@pytest.fixture
+def profiled(tmp_path):
+    """``profiled(fn)`` runs ``fn()`` under a ``jax.profiler`` trace written
+    to ``tmp_path`` and returns ``(fn's result, spans)``: each event of the
+    ``/host:CPU`` plane as ``(name, start_ns, dur_ns, {stat: value})``."""
+    def run(fn):
+        import jax
+        from jax.profiler import ProfileData
+
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            out = fn()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.rglob("*.xplane.pb")
+        with warnings.catch_warnings():  # jax's stats type lacks a module
+            warnings.simplefilter("ignore", DeprecationWarning)
+            spans = [(ev.name, ev.start_ns, ev.duration_ns, dict(ev.stats))
+                     for plane in ProfileData.from_file(str(path)).planes
+                     if plane.name == "/host:CPU"
+                     for line in plane.lines for ev in line.events]
+        return out, spans
+
+    return run

@@ -181,6 +181,69 @@ def test_jax_fleet_matches_per_node_scan_exactly():
         assert abs(node.switch_time_s - float(out["overhead_s"])) < 1e-6
 
 
+FLEET_SPANS = ("fleet.call", "fleet.workload", "fleet.slot_trace",
+               "fleet.stage", "fleet.scan", "fleet.dispatch",
+               "fleet.readback")
+
+
+def test_jax_fleet_spans_count_its_work(profiled):
+    """A profiled 2-node JAX fleet: every span of the call is in the
+    profiler's trace, nested as documented, and its counts agree with the
+    result; the result is bit-identical to an unprofiled call."""
+    pytest.importorskip("jax")
+    from repro.core import simkernel_jax as sj
+
+    asg = place("round-robin", 11, 2)
+    kw = dict(duration_s=5.0, backend="jax")
+    plain = simulate_fleet("lags", asg, **kw)
+    fleet, spans = profiled(lambda: simulate_fleet("lags", asg, **kw))
+    by = {}
+    for name, s, d, args in spans:
+        by.setdefault(name, []).append((s, s + d, args))
+    assert {n: len(by.get(n, ())) for n in FLEET_SPANS} == {
+        "fleet.call": 1, "fleet.workload": 2, "fleet.slot_trace": 2,
+        "fleet.stage": 1, "fleet.scan": 1, "fleet.dispatch": 1,
+        "fleet.readback": 1}
+    (call,) = by["fleet.call"]
+    assert call[2] == {"nodes": 2, "backend": "jax"}
+    for n in FLEET_SPANS[1:]:
+        assert all(call[0] <= s and e <= call[1] for s, e, _ in by[n])
+    (scan,), (disp,) = by["fleet.scan"], by["fleet.dispatch"]
+    assert scan[0] <= disp[0] and disp[1] <= scan[1]
+    assert scan[2] == {"nodes": 2, "ticks": int(5.0 / sj.TICK)}
+    (stage,) = by["fleet.stage"]
+    st = stage[2]
+    assert st["requests"] == fleet.n_arrived
+    assert sum(a["requests"] for *_, a in by["fleet.workload"]) \
+        == fleet.n_arrived
+    assert [a["fns"] for *_, a in by["fleet.workload"]] == [6, 5]
+    T, R = st["slots"], st["depth"]
+    assert T == 6 * 8 and st["padded"] == 2 * T * R
+    assert st["h2d_bytes"] == 2 * (T * R * 8 + T * 4)
+    built = [a for *_, a in by["fleet.slot_trace"]]
+    assert R == max(a["depth"] for a in built)
+    assert st["d2h_bytes"] == sum(a["h2d_bytes"] for a in built) == sum(
+        a["slots"] * (a["depth"] * 8 + 4) for a in built)
+    (rb,) = by["fleet.readback"]
+    assert rb[2]["d2h_bytes"] == 2 * (3 * T * R * 4 + T * 4 + 2 * 4)
+    for a, b in zip(plain.nodes, fleet.nodes):
+        assert a.latencies.tobytes() == b.latencies.tobytes()
+        assert a.fn_of.tobytes() == b.fn_of.tobytes()
+        assert (a.n_arrived, a.n_completed, a.busy_time_s,
+                a.switch_time_s) == (b.n_arrived, b.n_completed,
+                                     b.busy_time_s, b.switch_time_s)
+
+
+def test_numpy_fleet_opens_the_call_span(profiled):
+    pytest.importorskip("jax")
+    asg = place("round-robin", 6, 2)
+    _, spans = profiled(lambda: simulate_fleet("lags", asg, duration_s=2.0))
+    names = [n for n, *_ in spans if n.startswith("fleet.")]
+    assert names == ["fleet.call"]
+    assert [a for n, *_, a in spans if n == "fleet.call"] == [
+        {"nodes": 2, "backend": "numpy"}]
+
+
 def test_jax_fleet_agrees_with_numpy_fleet():
     """Backend-differential (same tolerances as test_simkernel_jax): the
     scan fleet and the tick fleet see the same cluster."""

@@ -1,6 +1,10 @@
 """repro.obs: histogram quantiles, trace export, schedstats conservation."""
 import json
 import math
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -9,6 +13,8 @@ import repro.obs as obs
 from repro.obs import recorder, report, tracing
 from repro.obs.metrics import Histogram, Registry
 from repro.obs.schedstats import SchedStats, from_sim_result
+
+SRC = Path(__file__).resolve().parents[1] / "src"
 
 
 @pytest.fixture(autouse=True)
@@ -98,8 +104,8 @@ def test_trace_export_roundtrip(tmp_path):
     with tr.span("outer", cat="test", k=1):
         with tr.span("inner"):
             pass
-    tr.instant("marker")
-    tr.counter("runq", depth=3)
+    tr.emit("marker", "obs", 5.0, 0.0, ph="i")
+    tr.emit("runq", "counter", 6.0, 0.0, {"depth": 3}, ph="C")
     path = tmp_path / "trace.json"
     tr.export(str(path))
     obj = json.loads(path.read_text())
@@ -124,7 +130,7 @@ def test_trace_export_roundtrip(tmp_path):
 def test_tracer_ring_is_bounded():
     tr = tracing.Tracer(capacity=16)
     for k in range(100):
-        tr.instant(f"e{k}")
+        tr.emit(f"e{k}", "obs", float(k), 0.0, ph="i")
     assert len(tr) == 16
     assert tr.dropped == 84
     names = [e["name"] for e in tr.events()]
@@ -139,6 +145,36 @@ def test_module_span_noop_when_disabled():
     with tracing.span("real"):
         pass
     assert len(obs.tracer()) == 1
+
+
+@pytest.mark.parametrize("ring", [False, True])
+def test_span_writes_into_the_profiler_trace(profiled, ring):
+    """The span reaches the profiler's host plane with its args as stats,
+    with or without a ring tracer; the ring records it as before."""
+    pytest.importorskip("jax")
+    if ring:
+        obs.install_tracer()
+
+    def work():
+        with obs.span("x", k=3) as sp:
+            sp.set_args(n=5)
+
+    _, spans = profiled(work)
+    (ev,) = [s for s in spans if s[0] == "x"]
+    assert ev[3] == {"k": 3, "n": 5}
+    if ring:
+        (rec,) = obs.tracer().events()
+        assert rec["name"] == "x" and rec["args"] == {"k": 3, "n": 5}
+    else:
+        assert obs.tracer() is None
+
+
+def test_import_obs_does_not_import_jax():
+    code = "import sys, repro.obs; print('jax' in sys.modules)"
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         capture_output=True, text=True,
+                         env=dict(os.environ, PYTHONPATH=str(SRC)))
+    assert out.stdout.strip() == "False"
 
 
 def test_fenced_span_measures_jax_work():
