@@ -88,16 +88,31 @@ def build_slot_trace(workload, n_fns: int, threads_per_fn: int) -> SlotTrace:
     return SlotTrace(jnp.asarray(at), jnp.asarray(de), jnp.asarray(slot_fn))
 
 
+def at_pointer(sel: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """Each slot's entry of ``x`` (T, R) at the one-hot pointer ``sel``
+    (T, R) as a masked sum over R: exact, since one term is kept.  A slot
+    whose pointer is past its last request (no ``sel`` entry) reads 0."""
+    return jnp.sum(jnp.where(sel, x, 0), axis=1)
+
+
 @partial(jax.jit, static_argnums=(1,))
 def simulate(trace: SlotTrace, p: SimParams):
-    """Returns dict of per-request completion ticks + node-level counters."""
+    """Returns dict of per-request completion ticks + node-level counters.
+
+    The tick body holds no gather and no scatter (``tests/
+    test_simkernel_jax.py`` guards the lowered program): every indexed
+    read or write is a dense masked select and reduce over a fixed axis —
+    the request axis at each slot's pointer, the group axis through the
+    slot-to-function one-hot, the slot axis through the cores' picks.
+    """
     T, R = trace.arrival_tick.shape
     C = p.n_cores
     spec = jb.spec_of(p.policy)
     slice_ticks = spec.slice_ticks
-    is_rt_fn = jnp.zeros(p.n_fns, bool)
-    if p.rt_fns:
-        is_rt_fn = is_rt_fn.at[jnp.asarray(p.rt_fns, jnp.int32)].set(True)
+    is_rt_fn = jnp.asarray(np.isin(np.arange(p.n_fns), p.rt_fns))
+    member = jb.group_member(trace.slot_fn, p.n_fns)  # (T, G)
+    req = jnp.arange(R)
+    slots = jnp.arange(T)
 
     def tick_body(state, tick):
         (ptr, rem, vrt_fn, load, credit, busy, ovh, done_tick,
@@ -105,18 +120,15 @@ def simulate(trace: SlotTrace, p: SimParams):
 
         # activate: slot idle (rem<=0, i.e. between requests) whose next
         # request has arrived
-        next_arr = jnp.take_along_axis(
-            trace.arrival_tick, ptr[:, None], axis=1
-        )[:, 0]
+        sel = req == ptr[:, None]  # (T, R): each slot's current request
+        next_arr = at_pointer(sel, trace.arrival_tick)
         can_start = (rem <= 0.0) & (next_arr <= tick) & (ptr < R)
-        new_dem = jnp.take_along_axis(trace.demand, ptr[:, None], axis=1)[:, 0]
+        new_dem = at_pointer(sel, trace.demand)
         rem = jnp.where(can_start, new_dem, rem)
         runnable = rem > 0.0
 
         # group stats (shared mechanism, not policy)
-        sib_count = jnp.zeros(p.n_fns).at[trace.slot_fn].add(
-            runnable.astype(jnp.float32)
-        )
+        sib_count = jb.to_groups(member, runnable.astype(jnp.float32))
         fn_runnable = sib_count > 0
 
         # policy key via the protocol backend; deterministic tie-break by
@@ -142,13 +154,11 @@ def simulate(trace: SlotTrace, p: SimParams):
         sticky = jb.sticky_mask(p.policy, view, continuing)
         key = jnp.where(sticky, key - 1e18, key)
 
-        # pick C best runnable
+        # pick C best runnable; each slot is picked by at most one core
         neg, idx = jax.lax.top_k(-key, C)
         picked = jnp.isfinite(-neg)  # (C,)
-        run_slots = jnp.where(picked, idx, -1)
-        picked_slot = jnp.zeros(T, bool).at[jnp.maximum(run_slots, 0)].set(
-            picked
-        )
+        picks = (idx[:, None] == slots) & picked[:, None]  # (C, T)
+        picked_slot = jnp.any(picks, axis=0)
 
         # slice bookkeeping
         slice_left = jnp.where(
@@ -162,8 +172,8 @@ def simulate(trace: SlotTrace, p: SimParams):
         n_grp = jnp.sum(fn_runnable)
         n_run = jnp.sum(runnable)
 
-        run_fn = trace.slot_fn[jnp.maximum(run_slots, 0)]
-        sibs = sib_count[run_fn]
+        # per-slot switch cost, used where the slot is picked
+        sibs = jb.to_entities(member, sib_count)
         n_wait = jnp.maximum(n_run - jnp.sum(picked), 0.0)
         p_pre = jnp.minimum(1.0, n_wait / (2.0 * C))
 
@@ -172,7 +182,7 @@ def simulate(trace: SlotTrace, p: SimParams):
         p_same_cfs = jnp.clip((sibs - 1.0) / jnp.maximum(n_run - 1.0, 1.0), 0, 1)
         cost_cfs = p_same_cfs * c_same + (1 - p_same_cfs) * c_cross
 
-        run_credit = credit[run_fn]
+        run_credit = jb.to_entities(member, credit)
         masked_cred = jnp.where(fn_runnable, credit, jnp.inf)
         wait_cmin = jnp.min(masked_cred)
         cost_us, spb = jb.voluntary_switch(
@@ -182,35 +192,27 @@ def simulate(trace: SlotTrace, p: SimParams):
         )
         cost_v = cost_us * 1e-6 * spb
 
-        eff = jnp.where(picked, TICK * (cfg_burst := p.burst_us * 1e-6)
+        eff = jnp.where(picked_slot, TICK * (cfg_burst := p.burst_us * 1e-6)
                         / (cfg_burst + cost_v), 0.0)
-        ovh = ovh + jnp.sum(jnp.where(picked, TICK - eff, 0.0))
-        busy = busy + jnp.sum(jnp.minimum(eff, rem[jnp.maximum(run_slots, 0)]
-                                          * picked))
+        ovh = ovh + jnp.sum(jnp.where(picked_slot, TICK - eff, 0.0))
+        busy = busy + jnp.sum(jnp.minimum(eff, rem * picked_slot))
 
         # progress
-        dec = jnp.zeros(T).at[jnp.maximum(run_slots, 0)].add(
-            eff * picked
-        )
-        new_rem = rem - dec
+        new_rem = rem - eff
         completed = (rem > 0.0) & (new_rem <= 0.0)
-        # record completion tick for the slot's current request
-        req_idx = jnp.arange(T) * R + jnp.minimum(ptr, R - 1)
-        done_flat = done_tick.at[req_idx].set(
-            jnp.where(completed, tick, done_tick[req_idx])
-        )
+        # record completion tick for the slot's current request (a slot
+        # that completes is runnable, so its pointer is below R)
+        done_tick = jnp.where(sel & completed[:, None], tick, done_tick)
         ptr = ptr + completed.astype(jnp.int32)
 
         # load credit
-        frac = jnp.zeros(p.n_fns).at[run_fn].add(
-            (eff / TICK) * picked
-        )
+        frac = jb.to_groups(member, eff / TICK)
         (load, credit), _ = lc.jax_tick((load, credit), frac, p.window_ticks)
 
         # fn vruntime advances by group core-time
-        vrt_fn = vrt_fn + jnp.zeros(p.n_fns).at[run_fn].add(eff * picked)
+        vrt_fn = vrt_fn + jb.to_groups(member, eff)
 
-        return (ptr, new_rem, vrt_fn, load, credit, busy, ovh, done_flat,
+        return (ptr, new_rem, vrt_fn, load, credit, busy, ovh, done_tick,
                 last_pick, slice_left, picked_slot), None
 
     init = (
@@ -221,7 +223,7 @@ def simulate(trace: SlotTrace, p: SimParams):
         jnp.zeros(p.n_fns),
         jnp.zeros(()),
         jnp.zeros(()),
-        jnp.full((T * R,), -1, jnp.int32),
+        jnp.full((T, R), -1, jnp.int32),
         jnp.zeros(T),  # last_pick tick
         jnp.zeros(T, jnp.int32),  # slice_left
         jnp.zeros(T, bool),  # prev_picked
@@ -230,7 +232,7 @@ def simulate(trace: SlotTrace, p: SimParams):
     (ptr, rem, vrt_fn, load, credit, busy, ovh, done,
      _last_pick, _slice_left, _prev_picked) = state
     return {
-        "done_tick": done.reshape(T, R),
+        "done_tick": done,
         "busy_s": busy,
         "overhead_s": ovh,
         "credit": credit,

@@ -11,6 +11,12 @@ Secondary tie-break in this backend: the slot id (added as ``idx * eps``
 by the simulator); the numpy backend uses thread-vruntime rank instead.
 Primary keys are identical across backends — that is the contract the
 differential tests pin (``tests/test_sched_backends.py``).
+
+Group values reach entities through a dense one-hot membership mask
+(:func:`group_member`, :func:`to_entities`, :func:`to_groups`), never a
+gather or scatter: under ``vmap`` inside the simulator's scan a TPU runs
+an indexed read with per-node indices nearly one element at a time, while
+a masked select and reduce over the group axis is elementwise work.
 """
 from __future__ import annotations
 
@@ -46,7 +52,8 @@ class PolicyView(NamedTuple):
     """Per-tick scheduling state handed to the key functions.
 
     Entity-level arrays are (T,) over request slots; group-level arrays
-    are (G,) over function/tenant cgroups, gathered via ``ent_group``.
+    are (G,) over function/tenant cgroups, expanded to entities through
+    ``ent_group`` (:func:`_of_group`).
     """
 
     ent_group: jnp.ndarray  # (T,) int32
@@ -60,25 +67,49 @@ class PolicyView(NamedTuple):
     slice_ticks: int  # python scalar (static)
 
 
+def group_member(ent_group: jnp.ndarray, n_groups: int) -> jnp.ndarray:
+    """(T, G) bool: entity ``t`` belongs to group ``g``."""
+    return ent_group[:, None] == jnp.arange(n_groups, dtype=ent_group.dtype)
+
+
+def to_entities(member: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
+    """``x[ent_group]`` as a masked reduce over groups; exact, since one
+    term per entity is kept and the rest are zero (or False)."""
+    if x.dtype == jnp.bool_:
+        return jnp.any(member & x, axis=1)
+    return jnp.sum(jnp.where(member, x, 0), axis=1)
+
+
+def to_groups(member: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """Per-group sum of entity values (a segment sum) as a masked reduce
+    over entities."""
+    return jnp.sum(jnp.where(member, v[:, None], 0), axis=0)
+
+
+def _of_group(v: PolicyView, x: jnp.ndarray) -> jnp.ndarray:
+    # ent_group is loop-invariant in the simulator: XLA hoists the compare
+    return to_entities(group_member(v.ent_group, x.shape[0]), x)
+
+
 def primary_key(code: int, v: PolicyView) -> jnp.ndarray:
     """(T,) primary key, lower runs first — jnp mirror of numpy_backend."""
-    g = v.ent_group
     if code == LAGS:
-        return v.group_credit[g]
+        return _of_group(v, v.group_credit)
     if code == RR:
         return v.last_pick_tick.astype(jnp.float32)
     if code == LAGS_STATIC:
-        is_rt = v.is_rt_group[g]
-        return jnp.where(is_rt, RT_BASE + v.last_pick_tick, v.group_vrt[g])
+        is_rt = _of_group(v, v.is_rt_group)
+        return jnp.where(is_rt, RT_BASE + v.last_pick_tick,
+                         _of_group(v, v.group_vrt))
     if code in (EEVDF, EEVDF_TUNED):
-        vrt = v.group_vrt[g]
+        vrt = _of_group(v, v.group_vrt)
         n_run = jnp.maximum(jnp.sum(v.group_runnable), 1)
         vmean = jnp.sum(jnp.where(v.group_runnable, v.group_vrt, 0.0)) / n_run
         deadline = vrt + v.slice_ticks * v.tick_sec
         inel = (vrt > vmean + CREDIT_EPS).astype(vrt.dtype)
         return inel * EEVDF_INELIGIBLE + deadline
     # CFS / CFS_TUNED
-    return v.group_vrt[g]
+    return _of_group(v, v.group_vrt)
 
 
 def sticky_mask(code: int, v: PolicyView, continuing: jnp.ndarray
@@ -93,13 +124,12 @@ def sticky_mask(code: int, v: PolicyView, continuing: jnp.ndarray
     """
     if code == LAGS:
         waiting = v.runnable & ~continuing
-        wait_cmin = jnp.min(
-            jnp.where(waiting, v.group_credit[v.ent_group], jnp.inf)
-        )
-        lighter_waits = v.group_credit[v.ent_group] > wait_cmin + CREDIT_EPS
+        credit = _of_group(v, v.group_credit)
+        wait_cmin = jnp.min(jnp.where(waiting, credit, jnp.inf))
+        lighter_waits = credit > wait_cmin + CREDIT_EPS
         return continuing & ~lighter_waits
     if code == LAGS_STATIC:
-        is_rt = v.is_rt_group[v.ent_group]
+        is_rt = _of_group(v, v.is_rt_group)
         rt_waiting = jnp.any(v.runnable & ~continuing & is_rt)
         return continuing & (is_rt | ~rt_waiting)
     # CFS/EEVDF slices are one tick by default; tuned variants and RR hold

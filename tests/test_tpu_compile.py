@@ -7,6 +7,8 @@ HBM).  The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and the suite runs on several
 workers.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -99,3 +101,22 @@ def test_stablelm_decode_step_fits_one_chip(one_chip):
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert total <= V5E_HBM_BYTES, (
         f"decode step needs {total / 1e9:.2f} GB of {V5E_HBM_BYTES / 1e9} GB")
+
+
+def test_fleet_scan_has_no_gather_or_scatter(one_chip):
+    """The vmapped fleet scan at the Fig 7 fleet's shape (10 nodes, 640
+    slots, 58 requests a slot) compiles with no gather or scatter: the TPU
+    runs one with per-node indices nearly an element at a time."""
+    from repro.core import simkernel_jax as sj
+
+    p = sj.SimParams(n_cores=12, n_fns=80, n_ticks=15000, policy=sj.LAGS,
+                     burst_us=280.0, depth=5.0)
+    trace = sj.SlotTrace(_sds(one_chip, (10, 640, 58), jnp.int32),
+                         _sds(one_chip, (10, 640, 58), jnp.float32),
+                         _sds(one_chip, (10, 640), jnp.int32))
+    f = jax.jit(lambda t: jax.vmap(lambda x: sj.simulate(x, p))(t))
+    hlo = f.lower(trace).compile().as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', hlo)
+    assert any("/while/body/" in n for n in op_names)
+    indexed = sorted({n for n in op_names if re.search("gather|scatter", n)})
+    assert not indexed, indexed
