@@ -8,9 +8,10 @@ For each seed it runs the cell's window at the cell's own load and prints
 one JSON line with every number the run compares (the lower readings) and
 the control's reading of the same number (the upper readings):
 
-* serving cells: ``logit_gap``'s control is the reference computed with
-  every matrix product in float8 e4m3 (the precision next below the
-  configuration's bfloat16), read at the same positions of the same
+* serving cells: ``logit_gap``'s control is the model reference's own
+  (its ``block_gaps`` with ``control``; for Qwen3, ``reference/decoder.py``
+  computes every matrix product in float8 e4m3, the precision next below
+  the configuration's bfloat16), read at the same positions of the same
   tokens: the gap of the token the control puts first.  ``tick_state_err``'s
   control is the reference tick in the precision next below the engine's
   tick (float32 below the host loop's float64; bfloat16 below the Pallas

@@ -8,20 +8,30 @@ is due, and stamps first tokens, later tokens and finishes by request id on
 the wall clock.  A token counts as delivered in the step in which the
 engine adds it to the request.
 
+What the engine does today, and so what the cell measures: every batch
+step decodes one token for all ``n_slots`` rows of the dense cache at one
+shared position, as a greedy stream; no prompt is prefilled on the device
+(the engine prices prefill on its cost model alone).
+
 Every batch step must decode on the device.  The engine's dense cache stops
 decoding once its position reaches ``max_len - 1``; decode work does not
 depend on the position (attention reads the whole cache under a length
-mask), so before that step the harness sets the position back to 0 through
-``Engine._cache_len`` and starts a new segment of the token stream.  A step
-that still ran no device decode, or whose logits were not finite, fails the
-requests it served.
+mask), so before that step the harness sets the position back to 0 and
+starts a new segment of the token stream.  A step that still ran no device
+decode, or whose logits were not finite, fails the requests it served.
+Every read and write of the engine's private state is in ``EngineDriver``.
+
+The configuration file decides the model: its ``model`` keys, and the
+first entry of its ``reference`` list, the model's reference module
+(``Model`` lists what that module provides).  A new model configuration
+adds its file and its module; nothing here changes.
 
 After the window closes the run checks what the timed path produced:
 
 * ``logit_gap``: each row's tokens of each segment are fed again, teacher
-  forced, through the plain float32 reference
-  (``reference/decoder.py``); the number is the widest gap by which a
-  served token's logit lies below the reference's best;
+  forced, through the model's plain float32 reference; the number is the
+  widest gap by which a served token's logit lies below the reference's
+  best;
 * ``admission_mismatches``: at steps drawn from the seed the harness
   records the engine's state before and after the step; the LAGS reference
   (``reference/lags.py``) must give the same batch, in order;
@@ -31,54 +41,121 @@ After the window closes the run checks what the timed path produced:
 from __future__ import annotations
 
 import contextlib
+import importlib
 import shutil
 import sys
 import time
+from pathlib import Path
 from typing import List
 
 import numpy as np
 
 from perfbench import core, traffic, tracereduce, weights
-from perfbench.reference import decoder, lags
+from perfbench.reference import lags
 
 SNAPSHOTS_PER_RUN = 24
 REF_ROWS = 4  # reference rows per block: the attention scores fit
+SLOW_STEP_S = 0.05  # a step this slow is named on standard error
 CLOCK = time.perf_counter
 
 
-def model_config(cell: core.Cell):
-    """The program's ``ModelConfig`` for the configuration file's model."""
-    from repro.configs.base import ModelConfig
+class Model:
+    """The configuration file's model, through its reference module.
 
-    m = cell.config["model"]
-    for key, want in (("model_type", "qwen3"), ("attention_bias", False),
-                      ("hidden_act", "silu"), ("use_sliding_window", False),
-                      ("rope_scaling", None)):
-        if m[key] != want:
-            raise core.BenchError(
-                f"the engine's decoder runs {key}={want!r}, the configuration "
-                f"states {m[key]!r}")
-    kw = dict(name=cell.config["name"], family="dense",
-              n_layers=m["num_hidden_layers"], d_model=m["hidden_size"],
-              n_heads=m["num_attention_heads"],
-              n_kv_heads=m["num_key_value_heads"], head_dim=m["head_dim"],
-              d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-              qk_norm=True, tie_embeddings=bool(m["tie_word_embeddings"]),
-              rope_theta=float(m["rope_theta"]),
-              norm_eps=float(m["rms_norm_eps"]),
-              dtype=m["torch_dtype"], param_dtype=m["torch_dtype"])
-    kw.update(cell.overrides.get("model", {}))
-    return ModelConfig(**kw)
+    ``ref`` is the module that the first entry of the file's ``reference``
+    list names (a path from the checkout's root).  It imports nothing of
+    the program and provides:
+
+    * ``program_config(model) -> dict``: the program's ``ModelConfig``
+      keywords but ``name``, for the file's ``model`` keys; it raises
+      ``core.BenchError`` for a key the program cannot run;
+    * ``sizes(model) -> tuple``: its static sizes, hashable;
+    * ``weight_specs(sz)``: the layout of the weights made from the seed
+      (``weights.WSpec`` leaves), as the program's parameter tree holds
+      them;
+    * ``block_gaps(params, tokens, served, *, sz, control, chunk)``: per
+      position of teacher-forced rows, the gap by which the served token's
+      logit lies below the reference's best, and with ``control`` the same
+      gap of the token the control puts first;
+    * ``decode_counts(sz, batch, pos) -> (flops, bytes)``: one decode step
+      of the batch at cache position ``pos``;
+    * ``token_flops(sz, pos)``: model operations of one token there.
+
+    ``cell.overrides["model"]`` (the CPU rehearsals' smaller sizes) is
+    laid over the file's ``model`` keys before any of these reads them.
+    """
+
+    def __init__(self, cell: core.Cell):
+        from repro.configs.base import ModelConfig
+
+        path = Path(cell.config["reference"][0])
+        self.ref = importlib.import_module(
+            ".".join(path.with_suffix("").parts))
+        keys = dict(cell.config["model"], **cell.overrides.get("model", {}))
+        self.sz = self.ref.sizes(keys)
+        self.mcfg = ModelConfig(name=cell.config["name"],
+                                **self.ref.program_config(keys))
+        self.weight_specs = self.ref.weight_specs(self.sz)
 
 
-def reference_sizes(mcfg, model: dict) -> tuple:
-    """The reference's sizes: the configuration file's, with the sizes the
-    rehearsal tests shrink taken from the config that was run."""
-    m = dict(model, num_hidden_layers=mcfg.n_layers,
-             hidden_size=mcfg.d_model, num_attention_heads=mcfg.n_heads,
-             num_key_value_heads=mcfg.n_kv_heads, head_dim=mcfg.head_dim,
-             intermediate_size=mcfg.d_ff, vocab_size=mcfg.vocab_size)
-    return decoder.sizes_from_config(m)
+class EngineDriver:
+    """Every read and write of ``Engine``'s private state that the harness
+    makes, in one place.  The engine has no public way to do these; an
+    engine change that renames or replaces one of these attributes (ROADMAP
+    R1: prefill on the device, a length per row) keeps or replaces the
+    method that uses it, and the cell measures the same work.
+
+    * ``_decode``, ``_tokens``, ``_cache`` (``warm_up``): set-up runs the
+      jitted device step once, so that the window compiles nothing, then
+      puts the tokens and the position back;
+    * ``_cache_len`` (``next_position``): the dense cache's position, which
+      the next step decodes at; read before each step for the reference's
+      segments and the counts, and set back to 0 before the cache fills
+      (module docstring);
+    * ``_tokens`` (``served``): the tokens a decoding step served, kept for
+      the check after the window;
+    * ``_cache``, ``_tokens`` (``free``): dropped after the window, so that
+      the reference runs with the program's state freed;
+    * ``_real_decode`` (``wrap_decode``): wrapped in the traced run to mark
+      the device decode with a span, and by the tests to plant faults;
+    * ``_parked`` (``parked``): requests backing off after a page
+      rejection; the LAGS reference admits from queues alone, so a
+      recorded step with any counts as a mismatch.
+    """
+
+    def __init__(self, eng):
+        self.eng = eng
+
+    def warm_up(self, params) -> None:
+        import jax
+        import jax.numpy as jnp
+
+        eng = self.eng
+        eng._tokens, finite, eng._cache = eng._decode(
+            params, eng._tokens, eng._cache, jnp.asarray(0))
+        bool(finite)
+        eng._tokens = jnp.zeros_like(eng._tokens)
+        eng._cache_len = 0
+        jax.block_until_ready(eng._cache)
+
+    def next_position(self, max_len: int) -> int:
+        if self.eng._cache_len >= max_len - 1:
+            self.eng._cache_len = 0  # a new segment: see the module docstring
+        return self.eng._cache_len
+
+    def served(self):
+        return self.eng._tokens
+
+    def parked(self) -> int:
+        return len(self.eng._parked)
+
+    def free(self) -> None:
+        self.eng._cache = None
+        self.eng._tokens = None
+
+    def wrap_decode(self, wrapper) -> None:
+        """Replace the device decode by ``wrapper(inner)``."""
+        self.eng._real_decode = wrapper(self.eng._real_decode)
 
 
 class _Spans:
@@ -102,7 +179,8 @@ def _useful(eng, tids) -> list:
     return [ents[t].useful_s if t in ents else 0.0 for t in tids]
 
 
-def _snap_before(eng) -> dict:
+def _snap_before(drv: EngineDriver) -> dict:
+    eng = drv.eng
     ts = eng.tenants
     tids = sorted(ts)
     return {
@@ -114,7 +192,7 @@ def _snap_before(eng) -> dict:
         "useful": _useful(eng, tids),
         "time": eng.stats.time_s,
         "batch_steps": eng.stats.batch_steps,
-        "parked": len(eng._parked),
+        "parked": drv.parked(),
     }
 
 
@@ -169,7 +247,8 @@ def token_segments(out: np.ndarray, pos: List[int]) -> List[tuple]:
             if b > a]
 
 
-def logit_gaps(params, sz, segs, max_len: int, control: bool = False):
+def logit_gaps(model: Model, params, segs, max_len: int,
+               control: bool = False):
     """Widest served-token gap over all segments and rows (and the
     control's, with ``control``)."""
     import jax.numpy as jnp
@@ -185,9 +264,9 @@ def logit_gaps(params, sz, segs, max_len: int, control: bool = False):
             rows = min(REF_ROWS, B - r0)
             tok[:rows, :n] = inputs[r0:r0 + rows]
             srv[:rows, :n] = served[r0:r0 + rows]
-            g, gc = decoder.block_gaps(params, jnp.asarray(tok),
-                                       jnp.asarray(srv), sz=sz,
-                                       control=control, chunk=chunk)
+            g, gc = model.ref.block_gaps(params, jnp.asarray(tok),
+                                         jnp.asarray(srv), sz=model.sz,
+                                         control=control, chunk=chunk)
             g, gc = np.asarray(g), np.asarray(gc)
             worst = max(worst, float(g[:rows, :n].max()))
             worst_c = max(worst_c, float(gc[:rows, :n].max()))
@@ -198,19 +277,19 @@ class Run:
     """One run of a serving cell: set-up, the window, the checks."""
 
     def __init__(self, cell: core.Cell, t_start: float, params=None):
-        import jax
-        import jax.numpy as jnp
-
         from repro.scheduler.tenant import Tenant
         from repro.serving.engine import Engine, EngineConfig
 
         self.cell, self.t_start = cell, t_start
+        # the set-up's parts, for the run's log line on standard error
+        marks = [("start", CLOCK())]  # imports and the TPU runtime's start
         self.ec = dict(cell.config["engine"], **cell.overrides.get("engine",
                                                                   {}))
-        self.mcfg = model_config(cell)
-        weights.check_layout(self.mcfg)
-        self.params = (weights.make(self.mcfg, cell.seed) if params is None
-                       else params)
+        self.model = Model(cell)
+        weights.check_layout(self.model.weight_specs, self.model.mcfg)
+        self.params = (weights.make(self.model.weight_specs, cell.seed)
+                       if params is None else params)
+        marks.append(("weights", CLOCK()))
         self.traffic = traffic.serving(cell.traffic, cell.seed, cell.seconds)
         tenants = {i: Tenant(i, weight_mb=w)
                    for i, w in enumerate(self.traffic.weight_mb)}
@@ -221,17 +300,15 @@ class Run:
             preempt_hysteresis=ec["preempt_hysteresis"],
             pallas_threshold=ec["pallas_threshold"],
             credit_window=ec["credit_window"]), tenants)
-        self.eng.attach_model(self.mcfg, self.params, max_len=ec["max_len"])
+        self.eng.attach_model(self.model.mcfg, self.params,
+                              max_len=ec["max_len"])
+        marks.append(("engine", CLOCK()))
+        self.driver = EngineDriver(self.eng)
         self.kernel_tick = (ec["pallas_threshold"] > 0
                             and len(tenants) >= ec["pallas_threshold"])
         # warm up the one decode shape, and the tick kernel where the
         # tenant count puts it on the path
-        eng = self.eng
-        eng._tokens, finite, eng._cache = eng._decode(
-            self.params, eng._tokens, eng._cache, jnp.asarray(0))
-        bool(finite)
-        eng._tokens = jnp.zeros_like(eng._tokens)
-        eng._cache_len = 0
+        self.driver.warm_up(self.params)
         if self.kernel_tick:
             from repro.sched import pallas_backend
 
@@ -239,19 +316,23 @@ class Run:
             pallas_backend.tick_and_pick(
                 np.zeros(T), np.zeros(T), np.zeros(T), np.zeros(T, bool),
                 ec["n_slots"], window=ec["credit_window"])
-        jax.block_until_ready(eng._cache)
-        self.setup_s = CLOCK() - t_start
+        marks.append(("warm-up", CLOCK()))
+        self.setup_s = marks[-1][1] - t_start
+        ends = [t_start] + [t for _, t in marks]
+        self.setup_parts = {k: b - a for (k, _), a, b
+                            in zip(marks, ends, ends[1:])}
 
     # -- the window ------------------------------------------------------
     def window(self) -> dict:
         from repro.scheduler.tenant import Request
 
         cell, eng, st, tr = self.cell, self.eng, self.eng.stats, self.traffic
+        drv = self.driver
         max_len = self.ec["max_len"]
         closed = cell.traffic["kind"] == "closed_loop"
         snap_p = min(1.0, SNAPSHOTS_PER_RUN / max(cell.seconds * 15.0, 1.0))
         snap_rng = np.random.default_rng([cell.seed % 2 ** 63, 1])
-        trace_lo, trace_hi = self._trace_span()
+        trace_lo = self._trace_span()[0]
         spans = _Spans(cell.trace)
         if cell.trace:
             self._wrap_for_trace(spans)
@@ -263,6 +344,7 @@ class Run:
         toks, pos_of = [], []
         snaps = []
         lateness = []
+        finished = {}  # request id -> seconds into the window
         sizes_next = {t: 0 for t in tr.sizes}
         outstanding, tokens, i, rid_next = 0, 0, 0, len(tr.arrivals)
         sched = tr.arrivals
@@ -293,16 +375,10 @@ class Run:
             if now >= end:
                 break
             rel = now - t0
-            if cell.trace and not tracing and rel >= trace_lo \
-                    and trace_hi > trace_lo:
+            if cell.trace and not tracing and rel >= trace_lo:
                 self._start_trace()
                 tracing, trace_t = True, spans("traced")
                 trace_t.__enter__()
-            elif tracing and rel >= trace_hi:
-                trace_t.__exit__(None, None, None)
-                self._stop_trace()
-                tracing = False
-                trace_hi = -1.0
             while i < len(sched) and sched[i].due_s <= rel:
                 a = sched[i]
                 submit(a.rid, a.tenant, a.prompt_len, a.max_new, a.due_s,
@@ -311,14 +387,14 @@ class Run:
                 outstanding += 1
             if outstanding == 0:
                 nxt = t0 + sched[i].due_s if i < len(sched) else end
+                if cell.trace and not tracing:
+                    nxt = min(nxt, t0 + trace_lo)  # start the trace on time
                 with spans("wait"):
                     time.sleep(max(0.0, min(nxt, end) - CLOCK()))
                 continue
-            if eng._cache_len >= max_len - 1:
-                eng._cache_len = 0  # a new segment: see the module docstring
-            pos = eng._cache_len
+            pos = drv.next_position(max_len)
             snap = snap_rng.random() < snap_p
-            before = _snap_before(eng) if snap else None
+            before = _snap_before(drv) if snap else None
             b0, d0 = st.batch_steps, st.device_decodes
             nf0 = st.nonfinite_decodes
             ts = CLOCK()
@@ -336,7 +412,7 @@ class Run:
                 step_rows.append(len(eng.running))
                 step_t.append(ts - t0)
             if decoded:
-                toks.append(eng._tokens)
+                toks.append(drv.served())
                 pos_of.append(pos)
             for r in eng.running:
                 if not ok:
@@ -349,6 +425,7 @@ class Run:
                 tokens += 1
                 if r.done:
                     outstanding -= 1
+                    finished[r.rid] = te - t0
                     if closed:
                         refill(r.tenant, te - t0)
                         outstanding += 1
@@ -370,16 +447,19 @@ class Run:
                     attempted=attempted, failed=len(bad), step_wall=step_wall,
                     step_pos=step_pos, step_rows=step_rows, step_t=step_t,
                     toks=toks, pos_of=pos_of, snaps=snaps,
-                    lateness=lateness,
+                    lateness=lateness, due=due, finished=finished,
                     decode_wall=list(st.decode_wall_s),
                     backoffs=st.backoffs)
 
     def _trace_span(self) -> tuple:
+        """The traced part of the window: its last ``trace.seconds`` (at
+        most a third of it), so that stopping the profiler, which takes
+        seconds, falls after the close and delays no request."""
         if not self.cell.trace:
             return 0.0, 0.0
-        tc = self.cell.config["trace"]
-        lo = min(tc["from_s"], self.cell.seconds / 3.0)
-        return lo, lo + min(tc["seconds"], self.cell.seconds / 3.0)
+        span = min(self.cell.config["trace"]["seconds"],
+                   self.cell.seconds / 3.0)
+        return self.cell.seconds - span, self.cell.seconds
 
     def _start_trace(self):
         import jax
@@ -395,13 +475,13 @@ class Run:
 
     def _wrap_for_trace(self, spans):
         """Mark the device decode, in the traced run only."""
-        inner_decode = self.eng._real_decode
+        def wrapper(inner_decode):
+            def real_decode():
+                with spans("decode"):
+                    inner_decode()
+            return real_decode
 
-        def real_decode():
-            with spans("decode"):
-                inner_decode()
-
-        self.eng._real_decode = real_decode
+        self.driver.wrap_decode(wrapper)
 
     # -- after the window ------------------------------------------------
     def checks(self, w: dict, control: bool = False) -> dict:
@@ -412,13 +492,10 @@ class Run:
         out = (np.asarray(jnp.concatenate(w["toks"], axis=1))
                if w["toks"] else np.zeros((self.ec["n_slots"], 0), np.int32))
         w["toks"] = None
-        eng = self.eng
-        eng._cache = None  # free the program's state before the reference
-        eng._tokens = None
+        self.driver.free()  # the program's state, before the reference
         segs = token_segments(out, w["pos_of"])
-        sz = reference_sizes(self.mcfg, self.cell.config["model"])
-        gap, gap_c = logit_gaps(self.params, sz, segs, self.ec["max_len"],
-                                control=control)
+        gap, gap_c = logit_gaps(self.model, self.params, segs,
+                                self.ec["max_len"], control=control)
         mism, err = scheduling_readings(w["snaps"], self.ec)
         if w["backoffs"]:
             mism += 1  # a rejected admission: the reference has no pages
@@ -436,7 +513,6 @@ class Run:
         return checks
 
     def record(self, w: dict, reduction) -> dict:
-        s = weights.sizes(self.mcfg)
         return {
             "kind": "engine", "setup_s": self.setup_s,
             "window_s": w["window_s"], "ttft_s": w["ttft"],
@@ -445,10 +521,27 @@ class Run:
             "step_rows": w["step_rows"], "step_t": w["step_t"],
             "decode_wall_s": w["decode_wall"],
             "n_tenants": len(self.eng.tenants), "n_slots": self.ec["n_slots"],
-            "kernel_tick": self.kernel_tick, "sizes": s,
-            "trace": reduction, "trace_span": self._trace_span(),
+            "kernel_tick": self.kernel_tick, "trace": reduction,
             "chips": self.cell.chips,
+            **model_counts(self.model, self.ec["n_slots"], w["step_pos"],
+                           w["step_t"], self._trace_span()),
         }
+
+
+def model_counts(model: Model, n_slots: int, step_pos, step_t,
+                 trace_span) -> dict:
+    """The model's counts for the readers: ``decode_counts``, (flops,
+    bytes) of each decode in the traced part of the window, and
+    ``token_flops``, one token's operations at each batch step's position
+    (0 for a step that did not decode)."""
+    lo, hi = trace_span
+    return {
+        "decode_counts": [model.ref.decode_counts(model.sz, n_slots, p)
+                          for p, t in zip(step_pos, step_t)
+                          if p >= 0 and lo <= t < hi],
+        "token_flops": [model.ref.token_flops(model.sz, p) if p >= 0 else 0
+                        for p in step_pos],
+    }
 
 
 def _bf16():
@@ -480,8 +573,19 @@ def run(cell: core.Cell, t_start: float) -> dict:
           f"{max(lateness) if lateness else 0.0:.4f}s slowest step "
           f"{walls[k]:.4f}s at {slow_at:.1f}s (its decode "
           f"{slow_decode:.4f}s); {watch.line()}", file=sys.stderr)
+    slow = [f"{s:.3f}s at {t:.1f}s" for t, s in zip(w["step_t"], walls)
+            if s > SLOW_STEP_S]
+    print(f"perfbench: {len(slow)} steps over {SLOW_STEP_S}s: "
+          f"{', '.join(slow[:8])}", file=sys.stderr)
+    print("perfbench: set-up {:.3f}s: {}".format(r.setup_s, ", ".join(
+        f"{k} {v:.3f}s" for k, v in r.setup_parts.items())), file=sys.stderr)
+    t_checks = CLOCK()
     checks = r.checks(w)
-    checks.pop("_counts")
+    counts = checks.pop("_counts")
+    print(f"perfbench: checked {counts['decodes']} decodes of "
+          f"{r.ec['n_slots']} rows in {counts['segments']} segments and "
+          f"{counts['snapshots']} recorded steps in "
+          f"{CLOCK() - t_checks:.1f}s", file=sys.stderr)
     rec = r.record(w, reduction)
     rec["peaks"] = core.peaks(device["kind"]) if device["platform"] == "tpu" \
         else None

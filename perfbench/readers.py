@@ -1,7 +1,9 @@
 """The arithmetic of the metric readers under ``metrics/``: each reader
 file names the quantity it reports and calls one of these on the run's
 record.  A function returns None where the record holds nothing to read
-(a cell of another kind, an untraced run, a chip with no peaks entry)."""
+(a cell of another kind, an untraced run, a chip with no peaks entry).
+A serving record carries the model's own counts (``engine_cell.Model``):
+``decode_counts`` per traced decode and ``token_flops`` per batch step."""
 from __future__ import annotations
 
 import numpy as np
@@ -53,24 +55,15 @@ def decode_device_ms(rec):
     return t / n * 1e3 if n > 0 else None
 
 
-def _traced_positions(rec):
-    lo, hi = rec["trace_span"]
-    return [p for p, t in zip(rec["step_pos"], rec["step_t"])
-            if p >= 0 and lo <= t < hi]
-
-
 def decode_roofline_pct(rec):
     tr = _traced(rec)
     if not _engine(rec) or not tr or not rec.get("peaks"):
         return None
     t, n = _module(tr, DECODE_MODULE)
-    pos = _traced_positions(rec)
-    if n <= 0 or not pos:
+    if n <= 0 or not rec["decode_counts"]:
         return None
-    s = rec["sizes"]
-    need = np.mean([counts.roofline_s(
-        *counts.decode_step(s, rec["n_slots"], p), rec["peaks"])
-        for p in pos])
+    need = np.mean([counts.roofline_s(f, b, rec["peaks"])
+                    for f, b in rec["decode_counts"]])
     return 100.0 * need / (t / n)
 
 
@@ -79,9 +72,8 @@ def step_mfu_pct(rec):
     window times the chips' peak."""
     if not _engine(rec) or not rec.get("peaks"):
         return None
-    s = rec["sizes"]
-    flops = sum(rows * counts.token_flops(s, p) for rows, p in
-                zip(rec["step_rows"], rec["step_pos"]) if p >= 0)
+    flops = sum(rows * f for rows, f in
+                zip(rec["step_rows"], rec["token_flops"]))
     return 100.0 * flops / (rec["window_s"] * rec["chips"]
                             * rec["peaks"]["bf16_flops_per_s"])
 

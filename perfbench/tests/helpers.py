@@ -2,14 +2,12 @@
 family in bfloat16 with an 8-position cache, ten times the offered rate,
 and a two-node fleet.
 
-The serving cell is built from its entries in
-``data/slice-48t-steady.json`` (ready, not yet registered: PERF.md, Open
-questions), with two variants that drive harness paths it does not: 1024
-tenants, so that the credit tick runs as the Pallas kernel (whose float32
-state error needs its own limit), and a closed loop that keeps every slot
-busy, so that the dense cache fills and restarts."""
+The serving cell is the registered ``slice-48t-steady``, with two variants
+that drive harness paths it does not: 1024 tenants, so that the credit
+tick runs as the Pallas kernel (whose float32 state error needs its own
+limit), and a closed loop that keeps every slot busy, so that the dense
+cache fills and restarts."""
 import time
-from pathlib import Path
 
 from perfbench import core
 
@@ -22,31 +20,19 @@ VARIANTS = {
                               limits={}),
 }
 
-# heads of the published width, and a width at which the tied head's
-# logits spread enough for the float8 control to move the first token
-SMALL_MODEL = dict(n_layers=2, d_model=512, n_heads=4, n_kv_heads=2,
-                   head_dim=128, d_ff=512, vocab_size=256)
-
-
-PREPARED = Path(__file__).parent / "data" / "slice-48t-steady.json"
-
-
-def serving_cell(seed: int, seconds: float, trace: bool) -> core.Cell:
-    prep = core.load_json(PREPARED)
-    bench = core.load_json(core.ROOT / "BENCHMARK.json")
-    bench = dict(bench, configs=bench["configs"] + [prep["config"]],
-                 end_to_end=bench["end_to_end"] + prep["end_to_end"],
-                 per_layer=bench["per_layer"] + prep["per_layer"])
-    return core.make_cell(prep["workload"], bench, seed, seconds, trace)
+# the configuration file's keys: heads of the published width, and a width
+# at which the tied head's logits spread enough for the float8 control to
+# move the first token
+SMALL_MODEL = dict(num_hidden_layers=2, hidden_size=512,
+                   num_attention_heads=4, num_key_value_heads=2,
+                   head_dim=128, intermediate_size=512, vocab_size=256)
 
 
 def small_cell(workload: str, seconds: float = 2.0, seed: int = 2 ** 33 + 5,
                trace: bool = False) -> core.Cell:
     variant = VARIANTS.get(workload)
-    if variant or workload == "slice-48t-steady":
-        cell = serving_cell(seed, seconds, trace)
-    else:
-        cell = core.load_cell(workload, seed, seconds, trace)
+    cell = core.load_cell("slice-48t-steady" if variant else workload, seed,
+                          seconds, trace)
     if variant:
         cell.workload = workload
         cell.traffic = dict(cell.traffic, **variant["traffic"])

@@ -31,27 +31,28 @@ def _greedy_stream(mcfg, params, steps, batch=4):
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-4), ("bfloat16", 0.2)])
 def test_served_tokens_sit_at_the_reference_best(dtype, tol):
     cell = small_cell("slice-48t-steady")
-    cell.overrides["model"].update(dtype=dtype, param_dtype=dtype)
-    mcfg = engine_cell.model_config(cell)
-    params = weights.make(mcfg, 77)
+    cell.overrides["model"].update(torch_dtype=dtype)
+    model = engine_cell.Model(cell)
+    assert model.ref is decoder
+    mcfg = model.mcfg
+    params = weights.make(model.weight_specs, 77)
     ins, outs = _greedy_stream(mcfg, params, 16)
-    sz = engine_cell.reference_sizes(mcfg, cell.config["model"])
     gap, _ = decoder.block_gaps(params, jnp.asarray(ins), jnp.asarray(outs),
-                                sz=sz, chunk=16)
+                                sz=model.sz, chunk=16)
     assert float(jnp.max(gap)) <= tol
     # a token altered where it is produced lies far below the best
     bad = (outs + 1) % mcfg.vocab_size
     gap_bad, _ = decoder.block_gaps(params, jnp.asarray(ins),
-                                    jnp.asarray(bad), sz=sz, chunk=16)
+                                    jnp.asarray(bad), sz=model.sz, chunk=16)
     assert float(jnp.max(gap_bad)) > 10 * max(float(jnp.max(gap)), 1e-3)
 
 
 def test_weights_match_the_program_layout():
     cell = small_cell("slice-48t-steady")
-    mcfg = engine_cell.model_config(cell)
-    weights.check_layout(mcfg)
-    p = weights.make(mcfg, 5)
-    q = weights.make(mcfg, 5)
+    model = engine_cell.Model(cell)
+    weights.check_layout(model.weight_specs, model.mcfg)
+    p = weights.make(model.weight_specs, 5)
+    q = weights.make(model.weight_specs, 5)
     assert all(bool(jnp.array_equal(a, b)) for a, b in
                zip(jax.tree_util.tree_leaves(p), jax.tree_util.tree_leaves(q)))
     assert p["embed"].dtype == jnp.bfloat16

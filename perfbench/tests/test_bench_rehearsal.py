@@ -37,15 +37,16 @@ def test_cell_runs_and_its_line_parses(workload, trace, capsys):
 
 def test_a_step_without_a_device_decode_fails_its_requests():
     r = engine_cell.Run(small_cell("slice-48t-steady"), time.perf_counter())
-    inner = r.eng._real_decode
     calls = {"n": 0}
 
-    def every_other():
-        calls["n"] += 1
-        if calls["n"] % 2:
-            inner()
+    def every_other(inner):
+        def decode():
+            calls["n"] += 1
+            if calls["n"] % 2:
+                inner()
+        return decode
 
-    r.eng._real_decode = every_other
+    r.driver.wrap_decode(every_other)
     w = r.window()
     assert w["failed"] > 0
     assert len(w["toks"]) < len(w["step_wall"])

@@ -96,3 +96,38 @@ def test_reduce_recorded_v5e_trace():
     assert all(n.startswith("bench.") for n in names)
     top = red["breakdown"]["device_ops"]
     assert [v for _, v in top] == sorted((v for _, v in top), reverse=True)
+
+
+# each serving per-layer reader on the recorded trace with a fixed window
+# of steps, at Qwen3-1.7B's sizes: the values the readers gave when they
+# counted the dense decoder themselves (before the model's reference module
+# gave the counts)
+PINNED = {
+    "engine.host_ms.steady": 7.250000000000006,
+    "decode.device_ms.steady": 33.083648000000004,
+    "decode_roofline": 13.388385671006983,
+    "step.mfu_pct.steady": 0.0018274250797252912,
+    "device.idle_pct.steady": 5.477247142857156,
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_serving_readers_on_the_recorded_trace(name):
+    from perfbench import core, engine_cell
+
+    ev = recorded()
+    red = tr.reduce(ev, tr.span_window(ev, "bench.traced"))
+    model = engine_cell.Model(core.load_cell("slice-48t-steady", 1, 51.0,
+                                             True))
+    steps = dict(step_wall_s=[0.070, 0.066, 0.064, 0.069, 0.071],
+                 step_pos=[99, 100, -1, 101, 102],
+                 step_rows=[16, 12, 16, 9, 16],
+                 step_t=[19.93, 20.0, 20.05, 20.07, 28.0])
+    rec = dict(steps, kind="engine", setup_s=12.5, window_s=51.0,
+               ttft_s=[0.1, 0.2], gaps_s=[0.05], tokens=2,
+               decode_wall_s=[0.062, 0.061, 0.0615, 0.0625], n_tenants=48,
+               n_slots=16, kernel_tick=False, trace=red, chips=1,
+               peaks=core.peaks("TPU v5 lite"),
+               **engine_cell.model_counts(model, 16, steps["step_pos"],
+                                          steps["step_t"], (20.0, 28.0)))
+    assert core.reader(name)(rec) == pytest.approx(PINNED[name], rel=1e-12)
